@@ -97,11 +97,12 @@ _drive._params = {}
 def run(out=None):
     rows = []
     results = {}
-    variants = [("dense", False, None),
-                ("paged-interpret", True, "interpret"),
-                ("paged-ref", True, "ref")]
-    if jax.default_backend() == "tpu":
-        variants.append(("paged-kernel", True, "kernel"))
+    # on a TPU only the compiled kernels may run (runner.default_attn_impl)
+    variants = [("dense", False, None)] + (
+        [("paged-kernel", True, "kernel")] if jax.default_backend() == "tpu"
+        else [("paged-interpret", True, "interpret"),
+              ("paged-ref", True, "ref")])
+    paged = variants[1][0]
     for name, device_cache, impl in variants:
         prev = os.environ.pop("REPRO_PAGED_IMPL", None)
         if impl:
@@ -116,7 +117,7 @@ def run(out=None):
                          "decode_tokens": toks, "batch": B}
         rows.append((f"engine/decode/{name}", 1e6 / tok_per_s,
                      f"tok_per_s={tok_per_s:.1f}"))
-    speedup = (results["paged-interpret"]["decode_tokens_per_s"]
+    speedup = (results[paged]["decode_tokens_per_s"]
                / results["dense"]["decode_tokens_per_s"])
     results["speedup"] = speedup
     results["backend"] = jax.default_backend()

@@ -111,11 +111,12 @@ _drive._params = {}
 def run(out=None):
     rows = []
     results = {}
-    variants = [("dense", False, None),
-                ("paged-interpret", True, "interpret"),
-                ("paged-ref", True, "ref")]
-    if jax.default_backend() == "tpu":
-        variants.append(("paged-kernel", True, "kernel"))
+    # on a TPU only the compiled kernels may run (runner.default_attn_impl)
+    variants = [("dense", False, None)] + (
+        [("paged-kernel", True, "kernel")] if jax.default_backend() == "tpu"
+        else [("paged-interpret", True, "interpret"),
+              ("paged-ref", True, "ref")])
+    paged = variants[1][0]
     for name, device_cache, impl in variants:
         prev = os.environ.pop("REPRO_PAGED_IMPL", None)
         if impl:
@@ -131,7 +132,7 @@ def run(out=None):
                          "ttft_mean_s": ttft_mean, "ttft_p90_s": ttft_p90}
         rows.append((f"engine/prefill/{name}", 1e6 / max(tok_per_s, 1e-12),
                      f"tok_per_s={tok_per_s:.1f} ttft_p90={ttft_p90:.3f}s"))
-    speedup = (results["paged-interpret"]["prefill_tokens_per_s"]
+    speedup = (results[paged]["prefill_tokens_per_s"]
                / results["dense"]["prefill_tokens_per_s"])
     results["speedup"] = speedup
     results["backend"] = jax.default_backend()

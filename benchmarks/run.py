@@ -38,6 +38,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated substring filter on module names")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     mods = QUICK if args.quick else MODULES
     if args.only:
         keys = args.only.split(",")

@@ -354,41 +354,71 @@ class PagedCache(PagedCacheBase):
         self._maybe_register(rid)
 
 
-_DEVICE_APPEND = None
-_DEVICE_COPY = None
+_POOL_JITS: dict = {}
 
 
-def _device_append(data, rows, slot_vec):
-    """Jitted pool-donating append: scatter ``rows`` at ``slot_vec`` into the
-    flattened [T*L*NB, bs, w] view of ``data`` and return it, in place."""
-    global _DEVICE_APPEND
-    if _DEVICE_APPEND is None:
+def _pool_jit(impl, *, donate: bool):
+    """Jit a pool helper on first use (host-only tools never import jax).
+    A donated pool is updated in place; an eager op would hold a second
+    copy of the whole pool for the duration."""
+    if impl not in _POOL_JITS:
         import jax
-        from repro.kernels.cache_write.ops import cache_write
-
-        def impl(data, rows, slot_vec):
-            T, L, NB, bs, w = data.shape
-            flat = data.reshape(T * L * NB, bs, w)
-            flat = cache_write(flat, rows, slot_vec, use_kernel=False)
-            return flat.reshape(T, L, NB, bs, w)
-
-        _DEVICE_APPEND = jax.jit(impl, donate_argnums=(0,))
-    return _DEVICE_APPEND(data, rows, slot_vec)
+        _POOL_JITS[impl] = jax.jit(impl,
+                                   donate_argnums=(0,) if donate else ())
+    return _POOL_JITS[impl]
 
 
-def _device_copy(data, src, dst):
-    """Jitted pool-donating block duplication (the COW copy): block columns
-    ``src`` land at ``dst`` in place — an eager ``.at[].set`` would copy the
-    whole pool buffer instead."""
-    global _DEVICE_COPY
-    if _DEVICE_COPY is None:
-        import jax
+def _move_blocks(dst, dst_idx, src_idx, src=None):
+    """``dst[:, :, dst_idx[i]] = src[:, :, src_idx[i]]`` for each i, one
+    block column at a time (``src=None``: from ``dst`` itself, whose
+    source and destination blocks must then be disjoint).  A gather or
+    scatter over the block axis would make XLA relayout the whole pool
+    into a pool-sized temporary first."""
+    import jax
 
-        def impl(data, src, dst):
-            return data.at[:, :, dst].set(data[:, :, src])
+    if dst_idx.shape[0] == 0:
+        return dst
 
-        _DEVICE_COPY = jax.jit(impl, donate_argnums=(0,))
-    return _DEVICE_COPY(data, src, dst)
+    def body(i, d):
+        col = jax.lax.dynamic_slice_in_dim(d if src is None else src,
+                                           src_idx[i], 1, axis=2)
+        return jax.lax.dynamic_update_slice_in_dim(d, col.astype(d.dtype),
+                                                   dst_idx[i], axis=2)
+
+    return jax.lax.fori_loop(0, dst_idx.shape[0], body, dst)
+
+
+def _append_impl(data, rows, slot_vec):
+    """Write ``rows`` at ``slot_vec`` of the flattened [T*L*NB, bs, w]
+    view of ``data`` with the fused cache-write kernel."""
+    from repro.kernels.cache_write.ops import cache_write
+
+    T, L, NB, bs, w = data.shape
+    flat = cache_write(data.reshape(T * L * NB, bs, w), rows, slot_vec)
+    return flat.reshape(T, L, NB, bs, w)
+
+
+def _copy_impl(data, src, dst):
+    """The COW copy: block columns ``src`` land at ``dst``."""
+    return _move_blocks(data, dst, src)
+
+
+def _import_impl(data, blocks, payload):
+    """The migration landing: payload block columns land at ``blocks``."""
+    import jax.numpy as jnp
+    n = blocks.shape[0]
+    return _move_blocks(data, blocks, jnp.arange(n, dtype=jnp.int32),
+                        src=payload)
+
+
+def _read_impl(data, blocks):
+    """The migration source read: block columns ``blocks``, in order."""
+    import jax.numpy as jnp
+    T, L, _, bs, w = data.shape
+    n = blocks.shape[0]
+    out = jnp.zeros((T, L, n, bs, w), data.dtype)
+    return _move_blocks(out, jnp.arange(n, dtype=jnp.int32), blocks,
+                        src=data)
 
 
 class DevicePagedCache(PagedCacheBase):
@@ -417,18 +447,18 @@ class DevicePagedCache(PagedCacheBase):
     def _copy_blocks(self, pairs: list):
         src = np.asarray([a for a, _ in pairs], np.int32)
         dst = np.asarray([b for _, b in pairs], np.int32)
-        self.data = _device_copy(self.data, self._jnp.asarray(src),
-                                 self._jnp.asarray(dst))
+        self.data = _pool_jit(_copy_impl, donate=True)(
+            self.data, self._jnp.asarray(src), self._jnp.asarray(dst))
 
     # -- host-interop append/gather (prefill staging, migration) ----------
     def append(self, rid: int, values):
         """values: [T, L, n_new, width] (np or jnp) appended at the tail.
 
-        Goes through the buffer-donating ``cache_write`` op (ref backend)
-        under a jit that owns the pool exclusively: one fused in-place
-        scatter instead of copying the whole pool.  (The reshape must stay
-        inside the jit — an eager reshape would create a second buffer
-        handle and defeat donation.)
+        Goes through the buffer-donating fused ``cache_write`` kernel under
+        a jit that owns the pool exclusively: one in-place write instead of
+        copying the whole pool.  (The reshape must stay inside the jit — an
+        eager reshape would create a second buffer handle and defeat
+        donation.)
         """
         jnp = self._jnp
         n_new = values.shape[2]
@@ -443,9 +473,8 @@ class DevicePagedCache(PagedCacheBase):
         slot_vec = (plane[:, :, None] + (blks * bs + offs)[None, None, :])
         rows = jnp.asarray(values, self.data.dtype).reshape(
             T * L * n_new, s.width)
-        self.data = _device_append(self.data, rows,
-                                   jnp.asarray(slot_vec.reshape(-1),
-                                               jnp.int32))
+        self.data = _pool_jit(_append_impl, donate=True)(
+            self.data, rows, jnp.asarray(slot_vec.reshape(-1), jnp.int32))
         self.lengths[rid] = start + n_new
         self._maybe_register(rid)
 
@@ -456,16 +485,18 @@ class DevicePagedCache(PagedCacheBase):
         return self.data[:, :, blks, offs]
 
     def read_blocks(self, rid: int):
-        table = np.asarray(self.tables.get(rid, []), np.int64)
-        return self.data[:, :, table]
+        table = np.asarray(self.tables.get(rid, []), np.int32)
+        return _pool_jit(_read_impl, donate=False)(self.data,
+                                                   self._jnp.asarray(table))
 
     def import_blocks(self, rid: int, length: int, payload):
         n_blocks = payload.shape[2]
         blocks = self._alloc(n_blocks)
         self.tables[rid] = blocks
         self.lengths[rid] = length
-        self.data = self.data.at[:, :, np.asarray(blocks, np.int64)].set(
-            self._jnp.asarray(payload, self.data.dtype))
+        self.data = _pool_jit(_import_impl, donate=True)(
+            self.data, self._jnp.asarray(np.asarray(blocks, np.int32)),
+            self._jnp.asarray(payload))
         self._maybe_register(rid)
 
     # -- decode hot path ---------------------------------------------------

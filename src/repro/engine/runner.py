@@ -52,12 +52,17 @@ def bucket_pow2(n: int) -> int:
 
 
 def default_attn_impl() -> str:
-    """Paged-kernel backend: compiled on TPU, interpret mode elsewhere.
-    Override with REPRO_PAGED_IMPL=kernel|interpret|ref."""
-    env = os.environ.get("REPRO_PAGED_IMPL")
-    if env:
-        return env
-    return "kernel" if jax.default_backend() == "tpu" else "interpret"
+    """Paged-kernel backend: the compiled kernels on a TPU, interpret mode
+    elsewhere, where REPRO_PAGED_IMPL=interpret|ref may pick the backend.
+    On a TPU any other REPRO_PAGED_IMPL than "kernel" is an error: an
+    emulated kernel there would run without saying so."""
+    env = os.environ.get("REPRO_PAGED_IMPL") or None
+    if jax.default_backend() == "tpu":
+        if env not in (None, "kernel"):
+            raise ValueError(f"REPRO_PAGED_IMPL={env!r} on a TPU: only the "
+                             f"compiled kernels run there")
+        return "kernel"
+    return env or "interpret"
 
 
 def _seq_layers(cfg: ModelConfig):

@@ -27,7 +27,7 @@ from __future__ import annotations
 import hashlib
 import itertools
 import time
-from collections import OrderedDict, deque
+from collections import Counter, OrderedDict, deque
 from dataclasses import dataclass, field, replace as dataclasses_replace
 from typing import Optional
 
@@ -126,8 +126,11 @@ class RealInstance:
         self.budgets = budgets
         self.policy = policy
         self.spec = spec                    # RoleSpec (hw/tp routing weights)
+        # the pools hold activations of the params' dtype (bf16 weights
+        # give bf16 KV and image pages)
         self.caches = R.RunnerCaches(cfg, kv_blocks=kv_blocks,
                                      img_blocks=img_blocks,
+                                     dtype=params["embed"].dtype,
                                      device=device_cache, sharing=sharing)
         self.runner = R.ModelRunner(cfg, params, self.caches)
         self.running: list[Request] = []
@@ -244,6 +247,7 @@ class HydraServer:
         self.slo = slo
         self.migrated_bytes = 0
         self.n_migrations = 0
+        self.migration_routes: Counter = Counter()  # (src, dst) role names
         self.on_event = None            # callable(StreamEvent) | None
         self.prefix_cache = prefix_cache
         self.embed_cache = EmbeddingCache(embed_cache_entries)
@@ -575,6 +579,7 @@ class HydraServer:
                 continue
             self.migrated_bytes += moved
             self.n_migrations += 1
+            self.migration_routes[(src.role_name, dst.role_name)] += 1
             if r.stage == Stage.PREFILL:
                 self._try_prefix_match(dst, it)
             # admit only under the destination's capacity reservation; a

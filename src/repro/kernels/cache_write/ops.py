@@ -7,20 +7,22 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.cache_write.kernel import cache_write_tpu
 from repro.kernels.cache_write.ref import cache_write_ref
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "use_kernel"),
                    donate_argnums=(0,))
-def cache_write(cache, new, slot_mapping, *, interpret: bool = True,
+def cache_write(cache, new, slot_mapping, *, interpret=None,
                 use_kernel: bool = True):
     if not use_kernel:
         return cache_write_ref(cache, new, slot_mapping)
-    return cache_write_tpu(cache, new, slot_mapping, interpret=interpret)
+    return cache_write_tpu(cache, new, slot_mapping,
+                           interpret=resolve_interpret(interpret))
 
 
-def paged_token_write(data, layer: int, rows, slots, *, interpret: bool = True,
+def paged_token_write(data, layer: int, rows, slots, *, interpret=None,
                       use_kernel: bool = True):
     """Append one token per request into every tensor of one layer of a
     ``[T, L, num_blocks, bs, width]`` paged store with ONE fused kernel
@@ -36,7 +38,7 @@ def paged_token_write(data, layer: int, rows, slots, *, interpret: bool = True,
                              interpret=interpret, use_kernel=use_kernel)
 
 
-def paged_chunk_write(data, layer: int, rows, slots, *, interpret: bool = True,
+def paged_chunk_write(data, layer: int, rows, slots, *, interpret=None,
                       use_kernel: bool = True):
     """Append a whole prefill *chunk* per request — C tokens each — into
     every tensor of one layer of a ``[T, L, num_blocks, bs, width]`` paged

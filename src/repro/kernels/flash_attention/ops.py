@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_tpu
 from repro.kernels.flash_attention.ref import flash_attention_ref
 
@@ -15,11 +16,11 @@ from repro.kernels.flash_attention.ref import flash_attention_ref
                                              "use_kernel"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True, use_kernel: bool = True):
+                    interpret=None, use_kernel: bool = True):
     """Public op.  q: [B, H, Sq, D]; k/v: [B, Kh, Sk, D].
 
-    ``interpret=True`` executes the Pallas kernel body in Python on CPU
-    (this container has no TPU); on TPU pass interpret=False.
+    ``interpret`` defaults to the platform: the compiled kernel on a TPU,
+    the Pallas interpreter (kernel body run in Python) elsewhere.
     """
     if not use_kernel:
         return flash_attention_ref(q, k, v, causal=causal, window=window)
@@ -35,6 +36,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     else:
         qp, kp, vp = q, k, v
     out = flash_attention_tpu(qp, kp, vp, causal=causal, window=window,
-                              block_q=bq, block_k=bk, interpret=interpret,
+                              block_q=bq, block_k=bk,
+                              interpret=resolve_interpret(interpret),
                               kv_len=Sk)
     return out[:, :, :Sq]

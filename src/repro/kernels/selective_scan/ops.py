@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.selective_scan.kernel import selective_scan_tpu
 from repro.kernels.selective_scan.ref import selective_scan_ref
 
@@ -13,7 +14,7 @@ from repro.kernels.selective_scan.ref import selective_scan_ref
 @functools.partial(jax.jit, static_argnames=("block_d", "chunk", "interpret",
                                              "use_kernel"))
 def selective_scan(dt, x, A, Bmat, Cmat, h0=None, *, block_d: int = 256,
-                   chunk: int = 256, interpret: bool = True,
+                   chunk: int = 256, interpret=None,
                    use_kernel: bool = True):
     if h0 is None:
         Bsz, _, d = x.shape
@@ -21,4 +22,5 @@ def selective_scan(dt, x, A, Bmat, Cmat, h0=None, *, block_d: int = 256,
     if not use_kernel:
         return selective_scan_ref(dt, x, A, Bmat, Cmat, h0)
     return selective_scan_tpu(dt, x, A, Bmat, Cmat, h0, block_d=block_d,
-                              chunk=chunk, interpret=interpret)
+                              chunk=chunk,
+                              interpret=resolve_interpret(interpret))

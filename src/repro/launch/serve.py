@@ -432,6 +432,8 @@ def main():
                     help="graceful-shutdown drain window in seconds "
                          "(HTTP front)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     (run_http if args.http else run_sim if args.sim else run_real)(args)
 
 

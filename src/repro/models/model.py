@@ -219,7 +219,8 @@ def _block_full(cfg, kind, p, shared, h, positions, enc_out, window,
 def encode_media(cfg, params, media):
     """The encode-stage computation: vision projector or audio encoder."""
     if cfg.frontend == "vision":
-        h = jax.nn.gelu((media @ params["media_proj_w1"]), approximate=True)
+        w1 = params["media_proj_w1"]
+        h = jax.nn.gelu(media.astype(w1.dtype) @ w1, approximate=True)
         return h @ params["media_proj_w2"]
     if cfg.frontend == "audio":
         enc = params["encoder"]
@@ -538,13 +539,16 @@ def sample_from_logits(logits, sample):
 # ---------------------------------------------------------------------------
 # decode over device-resident paged caches (DESIGN.md §11)
 # ---------------------------------------------------------------------------
-def paged_impl_flags(attn_impl: str) -> dict:
+def paged_impl_flags(attn_impl: Optional[str]) -> dict:
     """Map an engine-level backend name onto the kernel ops' flag pair.
 
+    None      : the Pallas kernels, compiled or interpreted by platform
     kernel    : compiled Pallas kernels (TPU)
     interpret : Pallas kernels in interpret mode (CPU parity/testing)
     ref       : pure-jnp oracles (fast CPU path, same paged semantics)
     """
+    if attn_impl is None:
+        return {"interpret": None, "use_kernel": True}
     if attn_impl == "kernel":
         return {"interpret": False, "use_kernel": True}
     if attn_impl == "interpret":
@@ -585,7 +589,7 @@ def _attn_decode_paged(p, x, cfg, data, layer, tables, slots, lens, window,
 
 
 def decode_step_paged(cfg: ModelConfig, params, data, ctl, state, lens,
-                      token, *, attn_impl: str = "interpret"):
+                      token, *, attn_impl: Optional[str] = None):
     """One decode step reading/writing device-resident paged caches in place.
 
     ``data``: {"kv": [T, L_kind, num_blocks+1, bs, width], "mla": ...}
@@ -866,7 +870,7 @@ def _cross_chunk(p, x, enc_out, cfg):
 
 
 def prefill_chunk_paged(cfg: ModelConfig, params, data, ctl, state, ctx_lens,
-                        tokens, *, attn_impl: str = "interpret"):
+                        tokens, *, attn_impl: Optional[str] = None):
     """One batched prefill chunk reading/writing device paged caches in place.
 
     The prefill analogue of :func:`decode_step_paged`: C tokens per request

@@ -260,13 +260,8 @@ def moe_ffn_shardmap(p, x, cfg, mesh):
                 P("model", None, None), P("model", None, None),
                 P(dp, "model", None))
     out_specs = (P(dp, "model", None), P())
-    if hasattr(_jax, "shard_map"):  # jax >= 0.6
-        fn = _jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_vma=False)
-    else:  # older jax: experimental module, check flag named check_rep
-        from jax.experimental.shard_map import shard_map as _shard_map
-        fn = _shard_map(body, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False)
+    fn = _jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                        out_specs=out_specs, check_vma=False)
     out, aux = fn(p["router"], p["moe_w_gate"], p["moe_w_up"],
                   p["moe_w_down"], x)
 

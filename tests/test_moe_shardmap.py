@@ -12,6 +12,7 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses, jax, jax.numpy as jnp
     from repro.configs import get_config
+    from repro.launch.mesh import make_mesh
     from repro.models import model as M, moe, sharding as SH
     from repro.train.train import loss_fn
 
@@ -24,7 +25,7 @@ SCRIPT = textwrap.dedent("""
     ref = M.forward(cfg, params, tokens)[0]
     g_ref = jax.grad(lambda p: loss_fn(cfg, p, batch, remat=False)[0])(params)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     SH.set_mesh(mesh)
     moe.MOE_SHARDMAP = True
     out = jax.jit(lambda p, t: M.forward(cfg, p, t)[0])(params, tokens)

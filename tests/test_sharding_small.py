@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import make_host_mesh, make_mesh
 from repro.models import model as M
 from repro.models import sharding as SH
 
@@ -42,7 +42,7 @@ def test_fsdp_shards_more():
     """FSDP must strictly reduce (or keep) per-device parameter bytes."""
     cfg = get_config("llama3-8b")
     pspec = M.param_specs(cfg, jnp.bfloat16)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def per_device_bytes(shardings):
         total = 0
