@@ -104,6 +104,9 @@ class Request:
     first_token_time: Optional[float] = None
     token_times: list = field(default_factory=list)
     stage_log: list = field(default_factory=list)  # (stage, t_start, t_end)
+    # when the request joined its current instance's queue (engine clock);
+    # the first batch that carries it logs "<stage>_queue" and clears it
+    queued_at: Optional[float] = None
     finish_time: Optional[float] = None
     finish_reason: Optional[str] = None  # "length"|"stop"|"abort"|"error"
 

@@ -97,8 +97,12 @@ class Engine:
                slo: Optional[SLO] = None,
                max_new_tokens: Optional[int] = None) -> int:
         """Enqueue a request into the live loop; returns its rid.  The
-        arrival timestamp is *now* on the engine clock (open-loop)."""
-        with self._cv:
+        arrival timestamp is *now* on the engine clock (open-loop), taken
+        once the engine lock is held: a scheduler iteration holds it
+        throughout, so the wait for it is a span of its own."""
+        with self.server.trace.span("submit.lock_wait"):
+            self._cv.acquire()
+        try:
             rid = self.server.submit(np.asarray(prompt), media=media,
                                      sampling=sampling, slo=slo,
                                      max_new_tokens=max_new_tokens,
@@ -106,6 +110,8 @@ class Engine:
             self._queues[rid] = deque()
             self._cv.notify_all()
             return rid
+        finally:
+            self._cv.release()
 
     def generate(self, prompt, *, media=None,
                  sampling: Optional[SamplingParams] = None,
@@ -200,9 +206,20 @@ class Engine:
         return self
 
     def _loop(self):
+        # one span per stretch without work: opened after the first step
+        # that finds none, closed when a step has built a batch again
+        idle = None
         while not self._stop_flag:
-            if not self.step():
+            if self.step():
+                if idle is not None:
+                    idle.__exit__(None, None, None)
+                    idle = None
+            else:
+                if idle is None:
+                    idle = self.server.trace.span("loop.idle").__enter__()
                 time.sleep(0.001)
+        if idle is not None:
+            idle.__exit__(None, None, None)
 
     def _live_rids(self) -> list:
         """Rids submitted but not yet finished (caller holds the lock)."""

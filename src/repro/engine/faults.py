@@ -3,8 +3,8 @@
 Disaggregated EPD serving multiplies failure domains: a single dead or
 wedged instance strands every request mid-pipeline and every migrated KV
 block on it.  This module holds the *leaf* pieces of the fault-tolerance
-layer — it imports nothing from the engine so every other engine module
-can depend on it:
+layer — it imports nothing from the engine but its tracing leaf
+(``engine/trace.py``), so every other engine module can depend on it:
 
   FaultPlan / FaultEvent   seeded, deterministic fault injection keyed on
                            the scheduler iteration counter: instance
@@ -38,6 +38,8 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 import numpy as np
+
+from repro.engine.trace import OFF, Trace
 
 # fault kinds
 CRASH = "crash"          # instance dies: all device state lost
@@ -215,25 +217,30 @@ class FaultPlan:
 # ---------------------------------------------------------------------------
 # transfer checksums (corruption *detection*; injection lives in the plan)
 # ---------------------------------------------------------------------------
-def _walk_arrays(payload, visit):
+def _walk_arrays(payload, visit, trace: Trace):
     """Deterministic traversal of a transfer payload: arrays directly, dict
-    trees in sorted key order, scalars by repr."""
+    trees in sorted key order, scalars by repr.  An array is copied to the
+    host first (span ``migrate.fetch``), then hashed (``migrate.hash``)."""
     if isinstance(payload, dict):
         for k in sorted(payload, key=str):
             visit(str(k).encode())
-            _walk_arrays(payload[k], visit)
+            _walk_arrays(payload[k], visit, trace)
     elif hasattr(payload, "shape"):
-        a = np.ascontiguousarray(np.asarray(payload))
-        visit(str((a.shape, a.dtype.str)).encode())
-        visit(a.tobytes())
+        with trace.span("migrate.fetch"):
+            a = np.ascontiguousarray(np.asarray(payload))
+            data = a.tobytes()
+        trace.count("migrate.host_bytes", len(data))
+        with trace.span("migrate.hash"):
+            visit(str((a.shape, a.dtype.str)).encode())
+            visit(data)
     else:
         visit(repr(payload).encode())
 
 
-def payload_checksum(payload) -> bytes:
+def payload_checksum(payload, trace: Trace = OFF) -> bytes:
     """End-to-end checksum of one store's transfer payload."""
     h = hashlib.blake2b(digest_size=16)
-    _walk_arrays(payload, h.update)
+    _walk_arrays(payload, h.update, trace)
     return h.digest()
 
 

@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.engine.faults import (TransferError, corrupt_payload,
                                  payload_checksum)
+from repro.engine.trace import OFF, Trace
 
 
 class BlockAllocator:
@@ -609,7 +610,8 @@ class StateStore:
 
 
 def migrate_request(rid: int, src, dst, *, fault: Optional[str] = None,
-                    timeout: Optional[float] = None) -> int:
+                    timeout: Optional[float] = None,
+                    trace: Trace = OFF) -> int:
     """Transactional pull-based migration (paper §4.3, hardened per
     DESIGN.md §15) over the unified interface.
 
@@ -632,6 +634,8 @@ def migrate_request(rid: int, src, dst, *, fault: Optional[str] = None,
     ``fault`` injects a wire failure for this attempt ("drop" loses the
     payload before import; "corrupt" bit-flips one payload so the checksum
     must catch it).  ``timeout`` bounds the whole transfer in seconds.
+    ``trace`` times the phases: ``migrate.read``, the checksums'
+    ``migrate.fetch`` and ``migrate.hash``, and ``migrate.import``.
     Returns bytes moved.
     """
     t0 = time.monotonic()
@@ -639,12 +643,13 @@ def migrate_request(rid: int, src, dst, *, fault: Optional[str] = None,
     moved = 0
     for s_cache, d_cache in zip(src, dst):                   # phase 1: read
         ctrl = s_cache.export_control(rid)
-        payload = s_cache.read_blocks(rid)
+        with trace.span("migrate.read"):
+            payload = s_cache.read_blocks(rid)
         if not isinstance(s_cache, PagedCacheBase):
             payload = dict(payload)        # snapshot the live StateStore dict
         moved += s_cache.nbytes(rid)
         staged.append([s_cache, d_cache, ctrl, payload,
-                       payload_checksum(payload)])
+                       payload_checksum(payload, trace)])
     if fault == "drop":
         raise TransferError("drop",
                             f"rid={rid}: transfer payload lost in flight")
@@ -656,14 +661,15 @@ def migrate_request(rid: int, src, dst, *, fault: Optional[str] = None,
     imported = []
     try:                                         # phase 2: verify + import
         for s_cache, d_cache, ctrl, payload, digest in staged:
-            if payload_checksum(payload) != digest:
+            if payload_checksum(payload, trace) != digest:
                 raise TransferError(
                     "corrupt", f"rid={rid}: transfer checksum mismatch")
             try:
-                if isinstance(s_cache, PagedCacheBase):
-                    d_cache.import_blocks(rid, ctrl["length"], payload)
-                else:
-                    d_cache.import_blocks(rid, payload)
+                with trace.span("migrate.import"):
+                    if isinstance(s_cache, PagedCacheBase):
+                        d_cache.import_blocks(rid, ctrl["length"], payload)
+                    else:
+                        d_cache.import_blocks(rid, payload)
             except MemoryError as e:
                 raise TransferError("oom", f"rid={rid}: {e}") from e
             imported.append(d_cache)

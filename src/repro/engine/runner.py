@@ -26,7 +26,6 @@ Decode and prefill each have two paths (DESIGN.md §11/§12):
 """
 from __future__ import annotations
 
-import functools
 import os
 from typing import Optional
 
@@ -39,6 +38,7 @@ from repro.configs.base import (ATTN_MLP, ATTN_MOE, MLA_MLP, MLA_MOE, MAMBA1,
 from repro.engine.paged_cache import (DevicePagedCache, PagedCache,
                                       PagedCacheSpec, StateStore,
                                       migrate_request)
+from repro.engine.trace import OFF, Trace
 from repro.models import mamba
 from repro.models import model as M
 
@@ -162,45 +162,60 @@ class RunnerCaches:
 
 
 def migrate(rid: int, src: RunnerCaches, dst: RunnerCaches, *,
-            fault=None, timeout=None) -> int:
+            fault=None, timeout=None, trace: Trace = OFF) -> int:
     return migrate_request(rid, src.stores, dst.stores, fault=fault,
-                           timeout=timeout)
+                           timeout=timeout, trace=trace)
 
 
 class ModelRunner:
     def __init__(self, cfg: ModelConfig, params, caches: RunnerCaches, *,
-                 attn_impl: Optional[str] = None):
+                 attn_impl: Optional[str] = None, trace: Trace = OFF):
         self.cfg = cfg
         self.params = params
         self.caches = caches
         self.attn_impl = attn_impl or default_attn_impl()
-        self._decode_jit = jax.jit(functools.partial(M.decode_step, cfg))
-        self._encode_jit = jax.jit(functools.partial(M.encode_media, cfg))
+        self.trace = trace
+        impl = self.attn_impl
+
+        # the steps are named functions, not partials or lambdas, so XLA
+        # names each program jit_<step> (a partial would be jit__unknown)
+        def decode(params, cache, lens, tok):
+            return M.decode_step(cfg, params, cache, lens, tok)
+
+        def encode(params, media):
+            return M.encode_media(cfg, params, media)
+
+        def paged_decode(params, data, ctl, state, lens, tok):
+            return M.decode_step_paged(cfg, params, data, ctl, state, lens,
+                                       tok, attn_impl=impl)
+
+        def prefill(params, data, ctl, state, lens, tokens):
+            return M.prefill_chunk_paged(cfg, params, data, ctl, state, lens,
+                                         tokens, attn_impl=impl)
+
+        def argmax(logits):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        self._decode_jit = jax.jit(decode)
+        self._encode_jit = jax.jit(encode)
         self._joint_jit = jax.jit(self._joint_fn)
         # device-paged decode: the cache buffers are donated so the
         # cache-write lands in place — without this every step would copy
         # the whole pool just to insert one row per request.  (Backends
         # without donation support fall back to a copy with a warning.)
-        self._paged_jit = jax.jit(
-            functools.partial(M.decode_step_paged, cfg,
-                              attn_impl=self.attn_impl),
-            donate_argnums=(1,))
+        self._paged_jit = jax.jit(paged_decode, donate_argnums=(1,))
         self._joint_paged_jit = jax.jit(self._joint_paged_fn,
                                         donate_argnums=(2,))
         # batched chunked prefill over the same device-resident caches
         # (DESIGN.md §12): the page pools are donated for the same reason
-        self._prefill_jit = jax.jit(
-            functools.partial(M.prefill_chunk_paged, cfg,
-                              attn_impl=self.attn_impl),
-            donate_argnums=(1,))
+        self._prefill_jit = jax.jit(prefill, donate_argnums=(1,))
         # standalone sampler for the dense fallback paths (the paged paths
         # fuse sampling into the step jit via ctl["sample"])
         self._sample_jit = jax.jit(M.sample_from_logits)
         # all-greedy fast path: plain on-device argmax over the no-sample
         # trace's logits — skips the top-k/top-p sorts entirely while still
         # sending only [B] ints to the host (two dispatches, zero copies)
-        self._argmax_jit = jax.jit(
-            lambda lg: jnp.argmax(lg, axis=-1).astype(jnp.int32))
+        self._argmax_jit = jax.jit(argmax)
 
     # ------------------------------------------------------------------
     # sampling control prep
@@ -246,6 +261,10 @@ class ModelRunner:
         """
         if not items:
             return
+        with self.trace.span("runner.encode"):
+            self._encode(items)
+
+    def _encode(self, items):
         groups: dict[tuple, list] = {}          # shape -> item indices
         for i, (_, m) in enumerate(items):
             groups.setdefault(m.shape, []).append(i)
@@ -391,6 +410,10 @@ class ModelRunner:
         short text chunk up to its length), batch-padded to a power of two;
         host caches fall back to the per-request dense path.
         """
+        with self.trace.span("runner.prefill"):
+            return self._prefill_chunks(items, sample)
+
+    def _prefill_chunks(self, items, sample):
         if not self.caches.device:
             lg = np.stack([self._prefill_chunk_dense(rid, toks, use_media=um)
                            for rid, toks, um in items])
@@ -559,6 +582,10 @@ class ModelRunner:
         """One decode step for a batch.  tokens: [B].  Returns logits [B, V],
         or sampled next-token ids [B] (np int32) when ``sample`` carries
         per-request sampling controls (see ``M.sample_from_logits``)."""
+        with self.trace.span("runner.decode"):
+            return self._decode(rids, tokens, sample)
+
+    def _decode(self, rids, tokens: np.ndarray, sample):
         if self.caches.device:
             return self._decode_paged(rids, tokens, sample)
         cfg = self.cfg
@@ -732,6 +759,10 @@ class ModelRunner:
             # (shape-grouped) encode separately and decode as usual
             self.encode(enc_items)
             return self.decode(rids, tokens, sample)
+        with self.trace.span("runner.joint"):
+            return self._joint(enc_items, rids, tokens, sample)
+
+    def _joint(self, enc_items, rids, tokens, sample):
         media = self._media_batch(enc_items)
         greedy = self._all_greedy(sample)
         if self.caches.device:

@@ -43,6 +43,7 @@ from repro.core.simulator import ROLE_SETS, DisaggConfig
 from repro.engine import runner as R
 from repro.engine.faults import (AdmissionError, FaultPlan, RequestJournal,
                                  TransferError)
+from repro.engine.trace import OFF, Trace
 
 
 @dataclass
@@ -79,6 +80,17 @@ def _media_hash(m) -> int:
     h.update(str((a.shape, a.dtype.str)).encode())
     h.update(a.tobytes())
     return int.from_bytes(h.digest(), "little")
+
+
+def _log_exec(r: Request, name: str, t0: float, t1: float):
+    """Log a stage's execution in ``r.stage_log``, one entry per stage: a
+    call that follows one of the same stage (the next prefill chunk or
+    decode step) extends that entry."""
+    log = r.stage_log
+    if log and log[-1][0] == name:
+        log[-1] = (name, log[-1][1], t1)
+    else:
+        log.append((name, t0, t1))
 
 
 class EmbeddingCache:
@@ -119,7 +131,7 @@ class RealInstance:
 
     def __init__(self, iid, role_name, cfg, params, budgets, policy,
                  *, kv_blocks=512, img_blocks=16, device_cache=True,
-                 spec=None, sharing=False):
+                 spec=None, sharing=False, trace: Trace = OFF):
         self.iid = iid
         self.role_name = role_name
         self.role = ROLE_SETS[role_name]
@@ -132,7 +144,7 @@ class RealInstance:
                                      img_blocks=img_blocks,
                                      dtype=params["embed"].dtype,
                                      device=device_cache, sharing=sharing)
-        self.runner = R.ModelRunner(cfg, params, self.caches)
+        self.runner = R.ModelRunner(cfg, params, self.caches, trace=trace)
         self.running: list[Request] = []
         self.waiting: deque = deque()
         # health state machine (DESIGN.md §15): healthy -> degraded -> dead
@@ -228,8 +240,11 @@ class HydraServer:
                  transfer_retries: int = 3, transfer_backoff: float = 0.005,
                  transfer_timeout: Optional[float] = None,
                  degraded_after: Optional[int] = 8,
-                 dead_after: Optional[int] = 32, max_recoveries: int = 5):
+                 dead_after: Optional[int] = 32, max_recoveries: int = 5,
+                 trace: bool = False):
         self.cfg = cfg
+        # spans and counters (engine/trace.py): off unless asked for
+        self.trace = Trace(trace)
         pol = POLICIES[policy]
         self.instances = []
         iid = itertools.count()
@@ -241,7 +256,7 @@ class HydraServer:
                     next(iid), role, cfg, params, budgets, pol,
                     kv_blocks=kv_blocks, img_blocks=img_blocks,
                     device_cache=device_cache, spec=spec,
-                    sharing=prefix_cache))
+                    sharing=prefix_cache, trace=self.trace))
         self.items: dict[int, ServeItem] = {}
         self._rid = itertools.count()
         self.slo = slo
@@ -328,6 +343,7 @@ class HydraServer:
         self._bind_keys(inst, it)
         if req.stage == Stage.PREFILL:
             self._try_prefix_match(inst, it)
+        req.queued_at = self.now()
         inst.enqueue(req)
         return rid
 
@@ -551,6 +567,17 @@ class HydraServer:
         different) destination — the source copy survives until an attempt
         fully lands.  Exhausted retries release the source and fall back to
         journal replay, so the request is never lost (DESIGN.md §15)."""
+        t0 = self.now()
+        with self.trace.span("migrate"):
+            landed = self._hand_off(r, src)
+        t1 = self.now()
+        r.stage_log.append(("migrate", t0, t1))
+        if landed:
+            r.queued_at = t1       # it waits on the destination from here
+
+    def _hand_off(self, r: Request, src: RealInstance) -> bool:
+        """The body of ``_migrate``; False when the request fell back to
+        journal replay."""
         src.remove(r)
         it = self.items[r.rid]
         last_kind = "?"
@@ -566,7 +593,8 @@ class HydraServer:
                      if self.fault_plan is not None else None)
             try:
                 moved = R.migrate(r.rid, src.caches, dst.caches,
-                                  fault=fault, timeout=self.transfer_timeout)
+                                  fault=fault, timeout=self.transfer_timeout,
+                                  trace=self.trace)
             except TransferError as e:
                 last_kind = e.kind
                 self.n_transfer_retries += 1
@@ -574,8 +602,9 @@ class HydraServer:
                 self._log("transfer_retry", rid=r.rid, fault=e.kind,
                           attempt=attempt, dst=dst.iid)
                 if attempt < self.transfer_retries:
-                    time.sleep(min(self.transfer_backoff * (2 ** attempt),
-                                   0.05))
+                    with self.trace.span("migrate.backoff"):
+                        time.sleep(min(self.transfer_backoff * (2 ** attempt),
+                                       0.05))
                 continue
             self.migrated_bytes += moved
             self.n_migrations += 1
@@ -589,13 +618,14 @@ class HydraServer:
                 dst.running.append(r)
             else:
                 dst.waiting.append(r)
-            return
+            return True
         # retries exhausted (or no destination): the source copy is of no
         # further use — release it and recover via journal replay
         self.n_transfer_failures += 1
         self._log("transfer_failed", rid=r.rid, fault=last_kind)
         src.caches.release(r.rid)
         self._replay(r, self.now())
+        return False
 
     # ------------------------------------------------------------------
     # sampling + event plumbing
@@ -654,12 +684,23 @@ class HydraServer:
         # include the compute that produced the token (the runner returns
         # host numpy, so the device work has completed by then)
         items = self.items
+        dec_reqs = list(batch.decode)
+        t_run = self.now()
+        # each request's wait on this instance ends with its first batch
+        for stage, reqs in (("encode", [r for r, _ in batch.encode]),
+                            ("prefill", [r for r, _ in batch.prefill]),
+                            ("decode", dec_reqs)):
+            for r in reqs:
+                if r.queued_at is not None:
+                    r.stage_log.append((f"{stage}_queue", r.queued_at,
+                                        t_run))
+                    r.queued_at = None
         # --- encode (+ joint with decode under hydra's parallel streams);
         # one encode item per image so multi-image requests batch flat
         enc_items = [(r.rid, m) for r, _ in batch.encode
                      for m in items[r.rid].media]
-        dec_reqs = list(batch.decode)
         dec_out = None
+        t_mid = None                  # end of encode, start of decode
         if inst.policy.parallel_streams and enc_items and dec_reqs:
             toks = np.array([items[r.rid].generated[-1] for r in dec_reqs])
             dec_out = inst.runner.joint_encode_decode(
@@ -668,6 +709,7 @@ class HydraServer:
         else:
             if enc_items:
                 inst.runner.encode(enc_items)
+            t_mid = self.now()
             if dec_reqs:
                 toks = np.array([items[r.rid].generated[-1] for r in dec_reqs])
                 dec_out = inst.runner.decode(
@@ -677,6 +719,8 @@ class HydraServer:
 
         # --- encode bookkeeping
         for r, _ in batch.encode:
+            _log_exec(r, "encode_exec", t_run,
+                      t_dec if t_mid is None else t_mid)
             if r.stage == Stage.ENCODE:
                 if self.prefix_cache:
                     self._cache_encoded(inst, r)
@@ -684,6 +728,7 @@ class HydraServer:
                 if Stage.PREFILL not in inst.role:
                     self._migrate(r, inst)
                 else:
+                    r.queued_at = t_dec
                     self._try_prefix_match(inst, items[r.rid])
 
         # --- chunked prefill: ONE batched runner call for every request's
@@ -704,11 +749,13 @@ class HydraServer:
                                            else 0)
                     t1 = min(t0 + chunk, len(it.prompt))
                     work.append((r, it.prompt[t0:t1], False, t1 - t0))
+            t_pre = self.now()
             pre_toks = inst.runner.prefill_chunks(
                 [(r.rid, toks, um) for r, toks, um, _ in work],
                 sample=self._sample_args([r for r, *_ in work]))
             now = self.now()
             for (r, _, _, done), tok in zip(work, pre_toks):
+                _log_exec(r, "prefill_exec", t_pre, now)
                 was_replay = r.replayed_tokens > 0
                 r.advance_after_prefill_chunk(done, now)
                 resumed = was_replay and r.replayed_tokens == 0
@@ -721,12 +768,16 @@ class HydraServer:
                         continue
                 if r.stage == Stage.DECODE and Stage.DECODE not in inst.role:
                     self._migrate(r, inst)
+                elif r.stage == Stage.DECODE:
+                    r.queued_at = now
                 elif r.stage == Stage.DONE:
                     self._retire(inst, r, now)
 
         # --- decode bookkeeping
         if dec_reqs and dec_out is not None:
             for r, tok in zip(dec_reqs, dec_out):
+                _log_exec(r, "decode_exec", t_run if t_mid is None else t_mid,
+                          t_dec)
                 if self._accept_token(r, int(tok), t_dec, first=False):
                     self._retire(inst, r, t_dec, reason="stop")
                     continue
@@ -864,6 +915,7 @@ class HydraServer:
         self._bind_keys(inst, it)
         if r.stage == Stage.PREFILL:
             self._try_prefix_match(inst, it)
+        r.queued_at = self.now()
         inst.enqueue(r)
 
     def _shed(self, r: Request, now: float, why: str = ""):
@@ -978,37 +1030,44 @@ class HydraServer:
             self._iter += 1
         plan = self.fault_plan
         any_work = False
-        for inst in list(self.instances):
-            if plan is not None and plan.crash(self._iter, inst.iid):
-                self._mark_dead(inst, t, cause="injected crash")
-                continue
-            if plan is not None and plan.stalled(self._iter, inst.iid):
-                # wedged: builds nothing this iteration; only count the
-                # missed heartbeat when it actually had runnable work
-                if self._has_ready_work(inst, t):
-                    self._health_no_progress(inst, t)
-                continue
-            batch = inst.policy.build(inst, t)
-            if batch.empty:
-                continue
-            any_work = True
-            inject_alloc = (plan is not None
-                            and plan.alloc_fail(self._iter, inst.iid))
-            pools = [c for c in (inst.caches.kv, inst.caches.mla,
-                                 inst.caches.img) if c is not None]
-            if inject_alloc:
-                for c in pools:
-                    c.fail_alloc = 1
-            try:
-                self._exec_batch(inst, batch, t)
-            except MemoryError:
-                self._recover_failed_batch(inst, batch, self.now())
-            else:
-                self._health_progress(inst)
-            finally:
+        span = None              # the step's span opens at its first batch
+        try:
+            for inst in list(self.instances):
+                if plan is not None and plan.crash(self._iter, inst.iid):
+                    self._mark_dead(inst, t, cause="injected crash")
+                    continue
+                if plan is not None and plan.stalled(self._iter, inst.iid):
+                    # wedged: builds nothing this iteration; only count the
+                    # missed heartbeat when it actually had runnable work
+                    if self._has_ready_work(inst, t):
+                        self._health_no_progress(inst, t)
+                    continue
+                batch = inst.policy.build(inst, t)
+                if batch.empty:
+                    continue
+                if span is None:
+                    span = self.trace.span("step").__enter__()
+                any_work = True
+                inject_alloc = (plan is not None
+                                and plan.alloc_fail(self._iter, inst.iid))
+                pools = [c for c in (inst.caches.kv, inst.caches.mla,
+                                     inst.caches.img) if c is not None]
                 if inject_alloc:
                     for c in pools:
-                        c.fail_alloc = 0
+                        c.fail_alloc = 1
+                try:
+                    self._exec_batch(inst, batch, t)
+                except MemoryError:
+                    self._recover_failed_batch(inst, batch, self.now())
+                else:
+                    self._health_progress(inst)
+                finally:
+                    if inject_alloc:
+                        for c in pools:
+                            c.fail_alloc = 0
+        finally:
+            if span is not None:
+                span.__exit__(None, None, None)
         if self.shed_policy == "deadline":
             self._shed_doomed(self.now() if now is None else now)
         return any_work
