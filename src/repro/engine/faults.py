@@ -24,7 +24,10 @@ layer — it imports nothing from the engine but its tracing leaf
                            greedy/seeded continuation
   payload_checksum         end-to-end checksum over a transfer payload
                            (numpy / jnp arrays or nested dict trees), how
-                           corrupted transfers are *detected*
+                           corrupted transfers are *detected*: a jax.Array
+                           leaf by an on-device positional digest
+                           (``transfer_digest``) whose bytes stay on the
+                           device, a numpy leaf by blake2b on the host
 
 Injection is deterministic by construction: a plan is a sorted set of
 (iteration, kind, instance) events, and ``FaultPlan.random`` derives one
@@ -32,10 +35,12 @@ from a seed, so a failing fault sweep reproduces from its seed alone.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import re
+import sys
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -217,14 +222,94 @@ class FaultPlan:
 # ---------------------------------------------------------------------------
 # transfer checksums (corruption *detection*; injection lives in the plan)
 # ---------------------------------------------------------------------------
-def _walk_arrays(payload, visit, trace: Trace):
+# seeds of the four lanes of the device digest (any distinct constants)
+_LANE_SEEDS = (0x243F6A88, 0x85A308D3, 0x13198A2E, 0x03707344)
+
+
+def _fmix32(x):
+    """murmur3's 32-bit finaliser: a bijection of uint32 that spreads
+    every input bit over the output."""
+    x = x ^ (x >> 16)
+    x = x * np.uint32(0x85EBCA6B)
+    x = x ^ (x >> 13)
+    x = x * np.uint32(0xC2B2AE35)
+    return x ^ (x >> 16)
+
+
+def _words(a):
+    """``a`` as uint32 words, elementwise: 4-byte elements bitcast,
+    narrower ones zero-extended, 8-byte ones split in two (a trailing
+    axis of 2)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    bits = 8 * min(a.dtype.itemsize, 4)
+    return lax.bitcast_convert_type(a, jnp.dtype(f"uint{bits}")).astype(
+        jnp.uint32)
+
+
+def transfer_digest(a):
+    """128-bit positional digest of one array, in one fused pass over its
+    bytes: four uint32 lanes, lane k = sum_i w_i * (fmix32(i ^ seed_k) | 1)
+    mod 2**32 over the words w_i at flat index i.  A multiplier is odd and
+    d * odd is never 0 mod 2**32 for 0 < |d| < 2**32, so any change to any
+    one word changes every lane; words moved between positions change the
+    digest unless the multipliers collide.  Shape and dtype are not in it:
+    the caller folds them in on the host."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    w = _words(a)
+    idx = jnp.zeros(w.shape, jnp.uint32)
+    stride = 1
+    for d in reversed(range(w.ndim)):
+        idx = idx + (lax.broadcasted_iota(jnp.uint32, w.shape, d)
+                     * np.uint32(stride % (1 << 32)))
+        stride *= w.shape[d]
+    return jnp.stack([
+        jnp.sum(w * (_fmix32(idx ^ np.uint32(s)) | np.uint32(1)),
+                dtype=jnp.uint32)
+        for s in _LANE_SEEDS])
+
+
+@functools.cache
+def _digest_jit():
+    """The jitted digest, built on first use (host-only tools never import
+    jax); one program per payload shape and dtype."""
+    import jax
+    return jax.jit(transfer_digest)
+
+
+def _is_device_array(x) -> bool:
+    """A ``jax.Array`` leaf.  jax is looked up, not imported: a process
+    that never imported it holds no device arrays."""
+    jax = sys.modules.get("jax")
+    return jax is not None and isinstance(x, jax.Array)
+
+
+class PayloadDigest(NamedTuple):
+    """One payload's checksum, its device part not yet fetched."""
+    host: bytes       # blake2b-16: structure, shapes, dtypes, numpy bytes
+    device: tuple     # one uint32[4] digest per jax.Array leaf, on device
+
+
+def _walk_arrays(payload, visit, device: list, trace: Trace):
     """Deterministic traversal of a transfer payload: arrays directly, dict
-    trees in sorted key order, scalars by repr.  An array is copied to the
-    host first (span ``migrate.fetch``), then hashed (``migrate.hash``)."""
+    trees in sorted key order, scalars by repr.  Every array's shape and
+    dtype go to ``visit``.  A jax.Array's digest is dispatched on its
+    device (span ``migrate.hash``, counter ``migrate.device_bytes``) and
+    appended to ``device``: its bytes never leave the device.  A numpy
+    array's bytes are copied out (span ``migrate.fetch``, counter
+    ``migrate.host_bytes``) and go to ``visit`` (``migrate.hash``)."""
     if isinstance(payload, dict):
         for k in sorted(payload, key=str):
             visit(str(k).encode())
-            _walk_arrays(payload[k], visit, trace)
+            _walk_arrays(payload[k], visit, device, trace)
+    elif _is_device_array(payload):
+        visit(str((payload.shape, payload.dtype.str)).encode())
+        with trace.span("migrate.hash"):
+            device.append(_digest_jit()(payload))
+        trace.count("migrate.device_bytes", payload.nbytes)
     elif hasattr(payload, "shape"):
         with trace.span("migrate.fetch"):
             a = np.ascontiguousarray(np.asarray(payload))
@@ -237,16 +322,41 @@ def _walk_arrays(payload, visit, trace: Trace):
         visit(repr(payload).encode())
 
 
+def payload_digest(payload, trace: Trace = OFF) -> PayloadDigest:
+    """Start the checksum of one store's transfer payload: the host part
+    is done, the device digests are dispatched and left on the device."""
+    h = hashlib.blake2b(digest_size=16)
+    device: list = []
+    _walk_arrays(payload, h.update, device, trace)
+    return PayloadDigest(h.digest(), tuple(device))
+
+
+def fetch_checksums(digests, trace: Trace = OFF) -> list:
+    """The checksums of ``digests`` as bytes: the host part, then 16 bytes
+    per device leaf.  Every device digest comes to the host in one
+    transfer (span ``migrate.fetch``, counter ``migrate.host_bytes``),
+    which waits for the device's reads and digests."""
+    parts = [d.device for d in digests]
+    if any(parts):
+        import jax
+        with trace.span("migrate.fetch"):
+            parts = jax.device_get(parts)
+        trace.count("migrate.host_bytes",
+                    sum(x.nbytes for p in parts for x in p))
+    return [d.host + b"".join(np.asarray(x).tobytes() for x in p)
+            for d, p in zip(digests, parts)]
+
+
 def payload_checksum(payload, trace: Trace = OFF) -> bytes:
     """End-to-end checksum of one store's transfer payload."""
-    h = hashlib.blake2b(digest_size=16)
-    _walk_arrays(payload, h.update, trace)
-    return h.digest()
+    return fetch_checksums([payload_digest(payload, trace)], trace)[0]
 
 
 def corrupt_payload(payload):
     """Return a bit-flipped copy of ``payload`` (the simulated wire
-    corruption a checksum must catch).  Dict trees corrupt their first
+    corruption a checksum must catch): the low 8 bits of the first
+    element of the first array leaf flip.  A jax.Array is flipped on its
+    device and comes back a jax.Array; dict trees corrupt their first
     array leaf; empty payloads come back unchanged."""
     if isinstance(payload, dict):
         for k in sorted(payload, key=str):
@@ -256,6 +366,16 @@ def corrupt_payload(payload):
                 out[k] = flipped
                 return out
         return payload
+    if _is_device_array(payload):
+        if not payload.size:
+            return payload
+        import jax.numpy as jnp
+        from jax import lax
+        u = lax.bitcast_convert_type(
+            payload, jnp.dtype(f"uint{8 * payload.dtype.itemsize}"))
+        first = (0,) * u.ndim
+        return lax.bitcast_convert_type(u.at[first].set(u[first] ^ 0xFF),
+                                        payload.dtype)
     if hasattr(payload, "shape"):
         a = np.array(np.asarray(payload), copy=True)
         if a.size:

@@ -37,7 +37,7 @@ from typing import Optional
 import numpy as np
 
 from repro.engine.faults import (TransferError, corrupt_payload,
-                                 payload_checksum)
+                                 fetch_checksums, payload_digest)
 from repro.engine.trace import OFF, Trace
 
 
@@ -618,16 +618,20 @@ def migrate_request(rid: int, src, dst, *, fault: Optional[str] = None,
     Three phases, so a failed transfer never strands the request:
 
     1. *read*: the source exports control info and bulk payloads for EVERY
-       store, and each payload is checksummed end-to-end (blake2b) —
-       StateStore payloads are snapshotted since ``read_blocks`` returns
-       the live dict.
-    2. *verify + import*: each payload is re-checksummed against its phase-1
-       digest (detecting wire corruption) and imported at the destination.
+       store, and each payload is checksummed end-to-end — StateStore
+       payloads are snapshotted since ``read_blocks`` returns the live
+       dict.  A device-array leaf is digested on its device (a 128-bit
+       positional digest that catches any change to any one word) and the
+       digest stays there; a numpy leaf is hashed on the host with blake2b
+       (``faults.payload_digest``).
+    2. *verify + import*: each payload is checksummed again (detecting
+       wire corruption); both sets of device digests come to the host in
+       one transfer and are compared there before anything is imported.
        Any failure — checksum mismatch, destination OOM, wall-clock timeout
-       — rolls back every import already landed and raises a typed
-       :class:`~repro.engine.faults.TransferError`; the SOURCE copy is
-       untouched, so the caller can retry against the same or another
-       destination.
+       — leaves no import landed (those before it are rolled back) and
+       raises a typed :class:`~repro.engine.faults.TransferError`; the
+       SOURCE copy is untouched, so the caller can retry against the same
+       or another destination.
     3. *release*: only after every store imported does the source release
        its references (blocks shared with other requests survive).
 
@@ -635,11 +639,11 @@ def migrate_request(rid: int, src, dst, *, fault: Optional[str] = None,
     payload before import; "corrupt" bit-flips one payload so the checksum
     must catch it).  ``timeout`` bounds the whole transfer in seconds.
     ``trace`` times the phases: ``migrate.read``, the checksums'
-    ``migrate.fetch`` and ``migrate.hash``, and ``migrate.import``.
+    ``migrate.hash`` and ``migrate.fetch``, and ``migrate.import``.
     Returns bytes moved.
     """
     t0 = time.monotonic()
-    staged = []           # (s_cache, d_cache, ctrl, payload, checksum)
+    staged = []           # (s_cache, d_cache, ctrl, payload, digest)
     moved = 0
     for s_cache, d_cache in zip(src, dst):                   # phase 1: read
         ctrl = s_cache.export_control(rid)
@@ -649,7 +653,7 @@ def migrate_request(rid: int, src, dst, *, fault: Optional[str] = None,
             payload = dict(payload)        # snapshot the live StateStore dict
         moved += s_cache.nbytes(rid)
         staged.append([s_cache, d_cache, ctrl, payload,
-                       payload_checksum(payload, trace)])
+                       payload_digest(payload, trace)])
     if fault == "drop":
         raise TransferError("drop",
                             f"rid={rid}: transfer payload lost in flight")
@@ -658,12 +662,16 @@ def migrate_request(rid: int, src, dst, *, fault: Optional[str] = None,
     if timeout is not None and time.monotonic() - t0 > timeout:
         raise TransferError("timeout",
                             f"rid={rid}: transfer exceeded {timeout}s")
+    sent = [digest for *_, digest in staged]              # phase 2: verify
+    received = [payload_digest(payload, trace)
+                for _, _, _, payload, _ in staged]
+    sums = fetch_checksums(sent + received, trace)
+    if sums[:len(sent)] != sums[len(sent):]:
+        raise TransferError("corrupt",
+                            f"rid={rid}: transfer checksum mismatch")
     imported = []
-    try:                                         # phase 2: verify + import
-        for s_cache, d_cache, ctrl, payload, digest in staged:
-            if payload_checksum(payload, trace) != digest:
-                raise TransferError(
-                    "corrupt", f"rid={rid}: transfer checksum mismatch")
+    try:                                         # ... + import
+        for s_cache, d_cache, ctrl, payload, _ in staged:
             try:
                 with trace.span("migrate.import"):
                     if isinstance(s_cache, PagedCacheBase):

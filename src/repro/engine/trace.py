@@ -20,8 +20,12 @@ Spans:
   migrate              ``HydraServer._migrate``: a whole hand-off, retries
                        included
   migrate.read         the source's device read dispatch
-  migrate.fetch        the device->host copy a checksum forces
-  migrate.hash         blake2b over the fetched bytes
+  migrate.fetch        the device->host copies the checksums force: a
+                       numpy leaf's bytes, or the 16-byte digests of the
+                       device leaves, whose fetch waits for the device's
+                       reads and digests
+  migrate.hash         blake2b over a numpy leaf's bytes, or the dispatch
+                       of a device leaf's digest
   migrate.import       the destination's import
   migrate.backoff      the sleep between transfer retries
   loop.idle            ``Engine._loop`` finding no work, until the next
@@ -30,6 +34,7 @@ Spans:
 Counters:
 
   migrate.host_bytes   bytes the transfer checksums pull to the host
+  migrate.device_bytes bytes the transfer checksums digest on the device
 
 Waits of single requests overlap, so they are not spans: the engine logs
 them in ``Request.stage_log`` (always on).

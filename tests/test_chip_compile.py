@@ -115,3 +115,17 @@ def test_pool_block_moves_stay_in_place(one_chip, helper):
     }[helper]
     compiled = jax.jit(impl, donate_argnums=donate).lower(*args).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("payload", ["kv", "image"])
+def test_transfer_digest_needs_no_payload_sized_temporary(one_chip, payload):
+    """The hand-off's on-device checksum of one request's KV payload (a
+    576-image + 256-token request) or image page reads the payload in a
+    fused pass: the words, indices and multipliers are never materialised."""
+    from repro.engine.faults import transfer_digest
+
+    shape = {"kv": (2, LAYERS, 53, PAGE, KH * D),
+             "image": (1, 1, 1, 576, 4096)}[payload]
+    x = jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+    compiled = jax.jit(transfer_digest).lower(x).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

@@ -90,10 +90,14 @@ def test_split_hand_offs_record_their_phases_and_host_bytes(llava):
     moves = [s for s in srv.trace.spans if s[0] == "migrate"]
     # two image requests hand off E->P and P->D, the text one P->D
     assert len(moves) == srv.n_migrations == 5
-    # each checksum pulls every payload array to the host, and each
-    # payload is checksummed twice (at the read and before the import)
+    # the device pools' payloads are digested on the device, each twice
+    # (at the read and before the import), and never copied to the host:
+    # one fetch a hand-off brings the 16-byte digests of its two device
+    # leaves (the KV and the image pool's payload), from both checksums
     assert srv.trace.counters == {
-        "migrate.host_bytes": 2 * srv.migrated_bytes}
+        "migrate.device_bytes": 2 * srv.migrated_bytes,
+        "migrate.host_bytes": srv.n_migrations * 2 * 2 * 16}
+    assert _names(srv.trace.spans).count("migrate.fetch") == len(moves)
     # the phases lie inside the hand-off spans
     parts = [s for s in srv.trace.spans if s[0].startswith("migrate.")]
     assert all(any(m0 <= p0 and p1 <= m1 for _, m0, m1 in moves)
