@@ -4,7 +4,13 @@
 model configuration, one traffic mix or one per-layer metric is a file of
 its own, found by the name that ``BENCHMARK.json`` gives it:
 
-  bench/configs/<config>.json     sizes as run, source, cuts, deployment
+  bench/configs/<config>.json     sizes as run, source, cuts, deployment,
+                                  and its language model's model_type
+  bench/arch/<model_type>.py      what the architecture asks of the
+                                  harness: the program's ModelConfig, the
+                                  weights' shapes, the bytes a token takes
+                                  in the pools, FLOP and byte counts, and
+                                  the plain f32 reference
   bench/traffic/<traffic>.json    lengths, arrivals, limits, sampling
   bench/metrics/<metric>.py       one reader per per-layer metric
 

@@ -2,8 +2,9 @@
 
 A cell is one entry of ``workloads``: a configuration and a traffic mix.
 Its configuration lives in the file that the ``configs`` entry names, its
-traffic in ``bench/traffic/<traffic>.json``, and each per-layer metric it
-reports in ``bench/metrics/<metric>.py``.
+traffic in ``bench/traffic/<traffic>.json``, each per-layer metric it
+reports in ``bench/metrics/<metric>.py``, and what its architecture
+(``model_type``) asks of the harness in ``bench/arch/<model_type>.py``.
 """
 from __future__ import annotations
 
@@ -49,22 +50,8 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
 
 
 def model_config(config: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
+    """The program's ``ModelConfig`` for a configuration file, from its
+    architecture (``bench/arch``)."""
+    from bench import arch
 
-    return ModelConfig(
-        name=config["name"], family="vlm",
-        num_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        num_heads=config["num_attention_heads"],
-        num_kv_heads=config["num_key_value_heads"],
-        head_dim=config["head_dim"],
-        d_ff=config["intermediate_size"],
-        vocab_size=config["vocab_size"],
-        act=config["hidden_act"],
-        norm_eps=config["rms_norm_eps"],
-        rope_theta=config["rope_theta"],
-        tie_embeddings=config["tie_word_embeddings"],
-        frontend="vision",
-        media_tokens=config["image_tokens"],
-        source=config["source"])
+    return arch.load(config).model_config(config)

@@ -14,6 +14,10 @@ With ``--trace 1`` only (they add host work and a device sync):
   migrations     ``HydraServer._migrate``: host time ended by
                  ``block_until_ready`` on every instance's pools (span
                  bench.migrate)
+
+A request's context is read from the instance's paged sequence pools
+(``kv`` for dense attention, ``mla`` for latent attention, whichever the
+architecture has).
 """
 from __future__ import annotations
 
@@ -57,9 +61,15 @@ def record_events(engine, rec: Record):
     engine.server.on_event = on_event
 
 
+def seq_pools(caches) -> list:
+    """An instance's paged sequence pools: ``kv`` and ``mla``, whichever
+    exist."""
+    return [c for c in (caches.kv, caches.mla) if c is not None]
+
+
 def _kv_len(caches, rid) -> int:
-    kv = caches.kv
-    return kv.lengths.get(rid, 0) if kv is not None else 0
+    pools = seq_pools(caches)
+    return pools[0].lengths.get(rid, 0) if pools else 0
 
 
 def instrument(engine, rec: Record, jax):
@@ -120,7 +130,8 @@ def instrument(engine, rec: Record, jax):
             migrate(r, src)
             jax.block_until_ready(
                 [c.data for i in server.instances
-                 for c in (i.caches.kv, i.caches.img) if c is not None])
+                 for c in (*seq_pools(i.caches), i.caches.img)
+                 if c is not None])
         with rec.lock:
             rec.migrations.append((r.rid, t0, time.perf_counter()))
 

@@ -5,13 +5,14 @@ traced window of a cell, and the per-layer readings taken from them.
     python3 bench/program.py --workload <cell> --seed <n> --seconds <s> \\
         [--program-trace 0|1]
 
-A run is ``bench/run.py --trace 1`` up to its readers, with the engine's
-trace switched on after the warm-up (``--program-trace 0`` leaves it off,
-to measure what it costs).  It checks no correctness; ``bench/run.py``
+A run is ``bench/run.py --trace 1`` up to its readers: the engine's trace
+is switched on after the warm-up, or left off with ``--program-trace 0``
+to measure what it costs.  It checks no correctness; ``bench/run.py``
 does.  The last line of stdout is a JSON object:
 
   per_layer   the cell's per-layer metrics, read as ``bench/run.py`` reads
-              them (the harness's own spans)
+              them; those in ``READERS`` are read by
+              ``bench/metrics/<name>.py`` through this module
   program     the readings of the program's spans (``READERS``), and the
               closure of the hand-off: ``hydra.migrate`` ms per request
               moved, and the share of it that its phases cover
@@ -26,7 +27,8 @@ does.  The last line of stdout is a JSON object:
               loaded from the persistent compile cache
 
 Program spans come twice: from the profiler's host planes (``hydra.*``,
-profiler clock, with the thread they ran on) and from ``Trace.spans``
+profiler clock, with the thread they ran on: ``Events.program_spans`` of
+``bench/trace.py``) and from ``Trace.spans``
 (``time.perf_counter``, the harness's clock).  Requests' waits come from
 their ``Request.stage_log`` (engine clock, shifted to the harness's).
 """
@@ -43,7 +45,6 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
-from dataclasses import dataclass, field  # noqa: E402
 from pathlib import Path  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -54,7 +55,6 @@ from bench import trace  # noqa: E402
 from bench.readings import Readings  # noqa: E402
 from bench.stats import quantile  # noqa: E402
 
-PREFIX = "hydra."
 ENGINE_LOOP = ("hydra.step", "hydra.loop.idle")
 MIGRATE_PARTS = ("migrate.read", "migrate.fetch", "migrate.hash",
                  "migrate.import", "migrate.backoff")
@@ -63,22 +63,6 @@ MIGRATE_PARTS = ("migrate.read", "migrate.fetch", "migrate.hash",
 # ---------------------------------------------------------------------------
 # what the program recorded
 # ---------------------------------------------------------------------------
-def read_spans(path: str) -> list:
-    """[(name, start ns, duration ns, thread)] of the ``hydra.*`` host
-    spans of a profiler capture, by start.  A host plane holds one line per
-    thread; ``thread`` names the line."""
-    from jax.profiler import ProfileData
-
-    out = []
-    for plane in ProfileData.from_file(path).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for k, line in enumerate(plane.lines):
-            out += [(e.name, e.start_ns, e.duration_ns, f"{plane.name}#{k}")
-                    for e in line.events if e.name.startswith(PREFIX)]
-    return sorted(out, key=lambda s: s[1])
-
-
 def snapshot(server) -> dict:
     """The server's spans, counters and stage logs, with the offset that
     takes its engine clock to ``time.perf_counter``."""
@@ -100,7 +84,7 @@ def idle_by_span(ev: trace.Events, spans, lo: float, hi: float) -> dict:
     """Device-idle seconds of [lo, hi) by the innermost engine-thread
     program span they fall in, or "no span": each idle gap is named by the
     span around its middle, as ``trace.breakdown`` names its idle gaps."""
-    eng = trace.Events(spans=[(n[len(PREFIX):], s, d)
+    eng = trace.Events(spans=[(n[len(trace.PROGRAM):], s, d)
                               for n, s, d, _ in engine_spans(spans)])
     gaps = trace.idle_gaps(ev, lo, hi)
     out: dict = {}
@@ -111,39 +95,17 @@ def idle_by_span(ev: trace.Events, spans, lo: float, hi: float) -> dict:
     return dict(sorted(out.items(), key=lambda kv: -kv[1]))
 
 
-@dataclass
-class ProgramReadings(Readings):
-    """``Readings`` with what the program recorded: ``program`` is a
-    ``snapshot``, ``program_spans`` the ``read_spans`` of the capture."""
-    program: dict = field(default_factory=dict)
-    program_spans: list = field(default_factory=list)
-
-    def spans_in_window(self, name: str) -> list:
-        """Durations (s) of the program's ``name`` spans that start in the
-        window, from ``Trace.spans``."""
-        return [t1 - t0 for n, t0, t1 in self.program.get("spans", ())
-                if n == name and self.t_open <= t0 < self.t_close]
-
-    def moved(self, window: bool = True) -> set:
-        """Rids with a hand-off (``stage_log`` "migrate") that starts in the
-        window, or at any time."""
-        off = self.program.get("clock", 0.0)
-        return {rid for rid, log in self.program.get("stage_logs", {}).items()
-                for n, t0, _ in log if n == "migrate"
-                and (not window or self.t_open <= t0 + off < self.t_close)}
-
-
 # ---------------------------------------------------------------------------
 # the readings; each returns None where it finds nothing to read
 # ---------------------------------------------------------------------------
-def lock_wait_p90_ms(r: ProgramReadings):
+def lock_wait_p90_ms(r: Readings):
     """Front end: P90 over the window's submits of ``hydra.submit.lock_wait``
     (``Engine.submit`` waiting for the engine lock), in ms."""
     waits = r.spans_in_window("submit.lock_wait")
     return 1e3 * quantile(waits, 0.9) if waits else None
 
 
-def engine_queue_p90_ms(r: ProgramReadings):
+def engine_queue_p90_ms(r: Readings):
     """Scheduler: P90 over the served requests of their first
     ``<stage>_queue`` wait in ``Request.stage_log`` (enqueue to the first
     batch that carries it), in ms."""
@@ -157,33 +119,35 @@ def engine_queue_p90_ms(r: ProgramReadings):
     return 1e3 * quantile(waits, 0.9) if waits else None
 
 
-def _per_moved_ms(r: ProgramReadings, name: str):
+def _per_moved_ms(r: Readings, name: str):
     moved = r.moved()
-    t = r.spans_in_window(name)
-    return 1e3 * sum(t) / len(moved) if moved and t else None
+    t = r.program_total(name)
+    return 1e3 * t / len(moved) if moved and t else None
 
 
-def fetch_ms_per_req(r: ProgramReadings):
-    """Migration: ``hydra.migrate.fetch`` (the device->host copies the
-    transfer checksums force) in the window, per request moved, in ms."""
+def fetch_ms_per_req(r: Readings):
+    """Migration: ``hydra.migrate.fetch`` (the one fetch of a hand-off's
+    digests on device pools; the payload copies for numpy leaves) in the
+    window, per request moved, in ms."""
     return _per_moved_ms(r, "migrate.fetch")
 
 
-def hash_ms_per_req(r: ProgramReadings):
-    """Migration: ``hydra.migrate.hash`` (blake2b over the fetched bytes)
-    in the window, per request moved, in ms."""
+def hash_ms_per_req(r: Readings):
+    """Migration: ``hydra.migrate.hash`` (the dispatch of each on-device
+    digest; blake2b over numpy leaves) in the window, per request moved,
+    in ms."""
     return _per_moved_ms(r, "migrate.hash")
 
 
-def host_mb_per_req(r: ProgramReadings):
+def host_mb_per_req(r: Readings):
     """Migration: counter ``migrate.host_bytes`` over the requests moved
     while the trace was on, in MB (1e6 bytes)."""
-    n = r.program.get("counters", {}).get("migrate.host_bytes")
+    n = r.program_total("migrate.host_bytes")
     moved = r.moved(window=False)
     return n / len(moved) / 1e6 if n and moved else None
 
 
-def idle_with_work_share(r: ProgramReadings):
+def idle_with_work_share(r: Readings):
     """Device: share of the window in which the chip was idle and the
     innermost engine-thread program span was not ``hydra.loop.idle``, in %:
     the idle time an engine change can remove."""
@@ -202,7 +166,7 @@ READERS = {"front.lock_wait_p90_ms": lock_wait_p90_ms,
            "device.idle_with_work_share": idle_with_work_share}
 
 
-def closure(r: ProgramReadings) -> dict:
+def closure(r: Readings) -> dict:
     """``hydra.migrate`` ms per request moved in the window, and the share
     of it that its phases (``MIGRATE_PARTS``) cover."""
     whole = sum(r.spans_in_window("migrate"))
@@ -297,13 +261,11 @@ def run_window(cell, seed: int, seconds: float, program_trace: bool, *,
                                    t_end, traffic["limits"])
     del engine
     gc.collect()
-    captures = sorted(Path(tracer.dir).rglob("*.xplane.pb"))
-    spans = read_spans(str(captures[-1]))
     ev = tracer.events()
-    r = ProgramReadings(config=config, device_kind=dev.device_kind,
-                        events=ev, rec=rec, reqs=reqs, submitted=submitted,
-                        t_open=t_open, t_close=t_close, program=program,
-                        program_spans=spans)
+    spans = ev.program_spans
+    r = Readings(config=config, device_kind=dev.device_kind, events=ev,
+                 rec=rec, reqs=reqs, submitted=submitted, t_open=t_open,
+                 t_close=t_close, program=program, program_spans=spans)
     idle = idle_by_span(ev, spans, r.lo, r.hi) if ev.ops else {}
     run.log("device idle by engine-thread program span (s): " + ", ".join(
         f"{k} {v:.3f}" for k, v in idle.items()))

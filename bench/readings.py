@@ -2,9 +2,16 @@
 
 A reader is a module with ``read(r: Readings) -> float | None``.  It
 returns None where it finds nothing to read (a stage that did not run, a
-kernel absent from the trace), and the harness then leaves the metric out
-of the result line; it never returns 0 for a share of a peak or of a
-roofline.
+kernel absent from the trace, a span the program did not record), and the
+harness then leaves the metric out of the result line; it never returns 0
+for a share of a peak or of a roofline.
+
+Besides the harness's own records, a traced run hands the readers what
+the program recorded (``repro.engine.trace``, on for the traced window):
+``program``, a ``bench.program.snapshot`` of the server (its spans on
+``time.perf_counter``, its counters, each request's ``stage_log``), and
+``program_spans``, its ``hydra.*`` spans in the profiler's capture.  A
+reader of a new counter or span needs only ``program_total``.
 """
 from __future__ import annotations
 
@@ -37,6 +44,8 @@ class Readings:
     submitted: list           # [(rid | None, submit-returned time)]
     t_open: float             # window, host clock (perf_counter)
     t_close: float
+    program: dict = field(default_factory=dict)       # snapshot
+    program_spans: list = field(default_factory=list)  # [(n, s, d, thread)]
     lo: float = field(init=False)     # window, trace clock (ns)
     hi: float = field(init=False)
 
@@ -71,6 +80,30 @@ class Readings:
         calls in the traced window."""
         return trace.op_seconds_in(
             self.events, [sp for _, sp in self.traced_calls(stage)], family)
+
+    def spans_in_window(self, name: str) -> list:
+        """Durations (s) of the program's ``name`` spans that start in the
+        window, from ``Trace.spans``."""
+        return [t1 - t0 for n, t0, t1 in self.program.get("spans", ())
+                if n == name and self.t_open <= t0 < self.t_close]
+
+    def program_total(self, name: str):
+        """The program's counter ``name`` over the traced run or, where it
+        has no such counter, the seconds of its ``name`` spans that start
+        in the window; None where it recorded neither."""
+        counters = self.program.get("counters", {})
+        if name in counters:
+            return counters[name]
+        spans = self.spans_in_window(name)
+        return sum(spans) if spans else None
+
+    def moved(self, window: bool = True) -> set:
+        """Rids with a hand-off (``stage_log`` "migrate") that starts in the
+        window, or at any time."""
+        off = self.program.get("clock", 0.0)
+        return {rid for rid, log in self.program.get("stage_logs", {}).items()
+                for n, t0, _ in log if n == "migrate"
+                and (not window or self.t_open <= t0 + off < self.t_close)}
 
     def due(self, k: int) -> float:
         return self.t_open + self.reqs[k].due

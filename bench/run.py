@@ -16,10 +16,12 @@ the greedy requests: ``correct`` says whether every served token's logit
 lies within the configuration's limit of the reference's best.
 
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1`` traces
-the window with the JAX profiler and reports the per-layer metrics, read
-by ``bench/metrics/<name>.py``, with the device's busy time and a
-breakdown.  The last line of stdout is the result; the last lines of
-stderr are the numbers compared for ``correct``, each beside its limit.
+the window with the JAX profiler, switches the engine's own spans and
+counters on (``repro.engine.trace``) after the warm-up, and reports the
+per-layer metrics, read by ``bench/metrics/<name>.py``, with the device's
+busy time and a breakdown.  The last line of stdout is the result; the
+last lines of stderr are the numbers compared for ``correct``, each
+beside its limit.
 
 Without a TPU, or with fewer chips than the cell asks for, the run exits
 non-zero and prints no result.
@@ -78,7 +80,9 @@ class CompileLog:
 # ---------------------------------------------------------------------------
 def pool_sizes(config: dict, dev, rehearse: bool):
     """(kv_blocks, img_blocks) per instance such that the three instances'
-    pools and the weights fit the device's ``bytes_limit``."""
+    pools and the weights fit the device's ``bytes_limit``.  Each of an
+    instance's sequence pools has kv_blocks blocks."""
+    from bench import arch
     from repro.engine import runner as R
 
     pools = config["pools"]
@@ -88,8 +92,8 @@ def pool_sizes(config: dict, dev, rehearse: bool):
     stats = dev.memory_stats()
     limit, in_use = stats["bytes_limit"], stats["bytes_in_use"]
     item = 2                                    # bf16 pools
-    kv_block = 2 * config["num_hidden_layers"] * R.KV_BLOCK \
-        * config["num_key_value_heads"] * config["head_dim"] * item
+    kv_block = R.KV_BLOCK * arch.load(config).seq_bytes_per_token(config,
+                                                                  item)
     img_block = config["image_tokens"] * config["hidden_size"] * item
     per_inst = (limit - in_use - RESERVE_BYTES) // 3
     kv_blocks = (per_inst - (img_blocks + 1) * img_block) // kv_block - 1
@@ -174,6 +178,14 @@ def _hold(cache, rid: int, n: int):
         cache.commit_prefill([rid], [n])
 
 
+def _hold_seq(caches, rid: int, n: int):
+    """Give ``rid`` n rows of every sequence pool of an instance."""
+    from bench.probes import seq_pools
+
+    for cache in seq_pools(caches):
+        _hold(cache, rid, n)
+
+
 def _sample(n: int, greedy: bool, np):
     return {"temp": np.full(n, 0.0 if greedy else 0.7, np.float32),
             "top_k": np.zeros(n, np.int32),
@@ -222,7 +234,7 @@ def warm_up(engine, plan: dict, image, np) -> int:
                 n_calls += 1
             for c, b, p in plan["text_chunks"]:
                 rids = [next(rid) for _ in range(b)]
-                _hold(inst.caches.kv, rids[0], p * bs - c)
+                _hold_seq(inst.caches, rids[0], p * bs - c)
                 inst.runner.prefill_chunks(
                     [(r, np.zeros(c, np.int32), False) for r in rids],
                     sample=_sample(b, greedy, np))
@@ -237,7 +249,7 @@ def warm_up(engine, plan: dict, image, np) -> int:
             continue
         for n_blocks in plan["kv_blocks_moved"]:
             r = next(rid)
-            _hold(inst.caches.kv, r, n_blocks * bs)
+            _hold_seq(inst.caches, r, n_blocks * bs)
             if n_img:
                 _hold(inst.caches.img, r, n_img)
             R.migrate(r, inst.caches, dst.caches)
@@ -248,7 +260,7 @@ def warm_up(engine, plan: dict, image, np) -> int:
         for b, p in plan["decode"]:
             for greedy in plan["variants"]:
                 rids = [next(rid) for _ in range(b)]
-                _hold(inst.caches.kv, rids[0], p * bs - 1)
+                _hold_seq(inst.caches, rids[0], p * bs - 1)
                 inst.runner.decode(rids, np.zeros(b, np.int32),
                                    sample=_sample(b, greedy, np))
                 for r in rids:
@@ -393,16 +405,18 @@ def check_sample(config, reqs, submitted, rec, picked, np) -> list:
 def reference_shape(config: dict, traffic: dict) -> dict:
     """Fixed shapes of the reference's calls for a cell: rows padded to
     the longest request the traffic can make, one read per output token."""
-    from bench.reference import Q_BLOCK
+    from bench import arch
 
+    block = arch.load(config).Q_BLOCK
     n_img = config["image_tokens"] if traffic["images_per_request"] else 0
     reads = traffic["output_tokens"]["max"]
     rows = n_img + traffic["prompt_tokens"]["max"] + reads - 1
-    return {"seq_len": -(-rows // Q_BLOCK) * Q_BLOCK, "reads": reads}
+    return {"seq_len": -(-rows // block) * block, "reads": reads}
 
 
 def judge(config, limits, shape, params, sample, np, reference) -> tuple:
-    """(correct, numbers) with numbers {name: (value, limit, rule)}."""
+    """(correct, numbers) with numbers {name: (value, limit, rule)};
+    ``reference`` is the architecture's module (``bench/arch``)."""
     numbers = {"checked_tokens": (int(sum(len(s["served"]) for s in sample)),
                                   limits["min_tokens"], ">=")}
     if sample:
@@ -442,7 +456,7 @@ def run_cell(cell, seed: int, seconds: float, trace_on: bool, *,
     import jax
     import numpy as np
 
-    from bench import reference, weights
+    from bench import arch, program, weights
     from bench.cell import model_config
     from bench.probes import Record, instrument, record_events
     from bench.traffic import make_window
@@ -475,6 +489,7 @@ def run_cell(cell, seed: int, seconds: float, trace_on: bool, *,
     tracer = None
     if trace_on:
         instrument(engine, rec, jax)
+        engine.server.trace.on = True
         tracer = Tracer(jax)
     c0 = compile_log.builds
     t_open, t_close, t_end, submitted = serve(engine, reqs, seconds,
@@ -500,6 +515,7 @@ def run_cell(cell, seed: int, seconds: float, trace_on: bool, *,
           flush=True)
     picked = pick_sample(reqs, rows, limits["requests"], seed, np)
     sample = check_sample(config, reqs, submitted, rec, picked, np)
+    recorded = program.snapshot(srv) if trace_on else {}
     # the program's state goes before the reference runs
     del engine, srv
     gc.collect()
@@ -521,7 +537,8 @@ def run_cell(cell, seed: int, seconds: float, trace_on: bool, *,
         ev = tracer.events()
         r = Readings(config=config, device_kind=dev.device_kind, events=ev,
                      rec=rec, reqs=reqs, submitted=submitted,
-                     t_open=t_open, t_close=t_close)
+                     t_open=t_open, t_close=t_close, program=recorded,
+                     program_spans=ev.program_spans)
         for m in cell.per_layer:
             v = load_reader(m["name"])(r)
             if v is not None:
@@ -533,7 +550,7 @@ def run_cell(cell, seed: int, seconds: float, trace_on: bool, *,
     t_ref = time.perf_counter()
     correct, numbers = judge(config, limits,
                              reference_shape(config, traffic), params,
-                             sample, np, reference)
+                             sample, np, arch.load(config))
     log(f"reference: {len(sample)} requests in "
         f"{time.perf_counter() - t_ref:.1f} s")
     result = {"correct": correct, "attempted": len(reqs),

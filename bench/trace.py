@@ -9,7 +9,12 @@ Events, all on the profiler's clock in nanoseconds:
 
   ops       per device: (name, start, duration) for every operation that
             ran on it
-  spans     host annotations: (name, start, duration)
+  spans     the harness's host annotations (``bench.*``): (name, start,
+            duration)
+  program_spans
+            the program's own host spans (``hydra.*``, ``repro.engine.trace``):
+            (name, start, duration, thread), where thread names the host
+            plane's line the span ran on
 
 The program's steps are jitted ``functools.partial`` objects, which XLA
 names ``_unknown``; so device work is attributed to a stage by the
@@ -31,6 +36,7 @@ from dataclasses import dataclass, field
 
 _OP_ID = re.compile(r"\.\d+$")
 WINDOW = "bench.window"
+PROGRAM = "hydra."
 
 
 def op_family(name: str) -> str:
@@ -43,19 +49,24 @@ def op_family(name: str) -> str:
 class Events:
     ops: dict = field(default_factory=dict)        # device -> [(n, s, d)]
     spans: list = field(default_factory=list)      # [(name, s, d)]
+    program_spans: list = field(default_factory=list)  # [(n, s, d, thread)]
 
     def to_json(self) -> dict:
-        return {"ops": self.ops, "spans": self.spans}
+        return {"ops": self.ops, "spans": self.spans,
+                "program_spans": self.program_spans}
 
     @classmethod
     def from_json(cls, d: dict) -> "Events":
         return cls(ops={k: [tuple(e) for e in v] for k, v in d["ops"].items()},
-                   spans=[tuple(e) for e in d["spans"]])
+                   spans=[tuple(e) for e in d["spans"]],
+                   program_spans=[tuple(e)
+                                  for e in d.get("program_spans", ())])
 
 
 def read_xplane(path: str, span_prefix: str = "bench.") -> Events:
-    """Device planes' ops, and the host spans whose names start with
-    ``span_prefix``; each list sorted by start."""
+    """Device planes' ops, the host spans whose names start with
+    ``span_prefix``, and the program's (``hydra.*``) with their thread;
+    each list sorted by start."""
     from jax.profiler import ProfileData
 
     ev = Events()
@@ -68,11 +79,16 @@ def read_xplane(path: str, span_prefix: str = "bench.") -> Events:
             if ops:
                 ev.ops[plane.name] = sorted(ops, key=lambda o: o[1])
         elif plane.name.startswith("/host:"):
-            for line in plane.lines:
+            for k, line in enumerate(plane.lines):
                 for e in line.events:
                     if e.name.startswith(span_prefix):
                         ev.spans.append((e.name, e.start_ns, e.duration_ns))
+                    elif e.name.startswith(PROGRAM):
+                        ev.program_spans.append(
+                            (e.name, e.start_ns, e.duration_ns,
+                             f"{plane.name}#{k}"))
     ev.spans.sort(key=lambda s: s[1])
+    ev.program_spans.sort(key=lambda s: s[1])
     return ev
 
 

@@ -15,9 +15,10 @@ what a lone request sees at the cell's shapes, from which the limits are
 set.
 
 ``check`` runs, for each seed, a window at the cell's own rate and
-compares the served tokens with the plain f32 reference, as a run does;
-then it puts the reference's float8 forward in the program's place on the
-same sample (the control).  It prints each seed's widest gap of both: the
+compares the served tokens with the plain f32 reference of the
+configuration's architecture (``bench/arch``), as a run does; then it
+puts the reference's float8 forward in the program's place on the same
+sample (the control).  It prints each seed's widest gap of both: the
 limit lies between the program's largest and the control's smallest.
 
 Neither is part of a benchmark run; both print their readings for
@@ -129,9 +130,10 @@ def sweep(cell, config, args, jax, np):
 
 
 def check(cell, config, args, jax, np):
-    from bench import reference, run
+    from bench import arch, run
     from bench.traffic import make_window
 
+    reference = arch.load(config)
     limits = {**config["check"], **cell.traffic["check"]}
     shape = run.reference_shape(config, cell.traffic)
     for seed in [int(s) for s in args.seeds.split(",")]:

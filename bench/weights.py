@@ -1,44 +1,28 @@
 """Random weights from the seed, made on the device in one jitted call, in
 the layout the program serves (``repro.models.model`` parameter tree) and
-in the type it serves them in (bf16 matrices, f32 norm offsets).
+in the type it serves them in.
 
-The norm weights are offsets: the program scales by ``1 + w``.  They are
-drawn small and nonzero, N(0, 0.1^2), so that the reference is checked on
-that path too.  Matrices are N(0, 1/fan_in); the embedding is N(0, 1) and
-the output head N(0, 1/d), which gives logits a spread of about 1.
+The configuration's architecture (``bench/arch``) gives the leaf tables:
+name -> (shape, std, kind), for the top level and for each layer, so that
+layers may differ.  A leaf is N(0, std^2); kind "w" is made in bfloat16
+(matrices), any other kind in float32 (norm offsets, routers).
 
-The benchmark makes these weights; the reference (``bench/reference.py``)
-reads the same tree, and imports nothing of the program.
+The benchmark makes these weights; the reference reads the same tree, and
+imports nothing of the program.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 
+from bench import arch
+
 
 def shapes(config: dict) -> dict:
-    """Leaf name -> (shape, std, dtype name) in the program's layout."""
-    d, V = config["hidden_size"], config["vocab_size"]
-    H, Kh = config["num_attention_heads"], config["num_key_value_heads"]
-    Dh, F = config["head_dim"], config["intermediate_size"]
-    top = {"embed": ((V, d), 1.0, "w"), "final_norm": ((d,), 0.1, "n"),
-           "lm_head": ((d, V), 1 / math.sqrt(d), "w"),
-           # the encode stage's projector: d -> 2d -> d, GELU (tanh)
-           "media_proj_w1": ((d, 2 * d), 1 / math.sqrt(d), "w"),
-           "media_proj_w2": ((2 * d, d), 1 / math.sqrt(2 * d), "w")}
-    layer = {"norm1": ((d,), 0.1, "n"), "norm2": ((d,), 0.1, "n"),
-             "wq": ((d, H * Dh), 1 / math.sqrt(d), "w"),
-             "wk": ((d, Kh * Dh), 1 / math.sqrt(d), "w"),
-             "wv": ((d, Kh * Dh), 1 / math.sqrt(d), "w"),
-             "wo": ((H * Dh, d), 1 / math.sqrt(H * Dh), "w"),
-             "w_gate": ((d, F), 1 / math.sqrt(d), "w"),
-             "w_up": ((d, F), 1 / math.sqrt(d), "w"),
-             "w_down": ((F, d), 1 / math.sqrt(F), "w")}
-    return {"top": top, "layer": layer,
-            "layers": config["num_hidden_layers"]}
+    """{"top": leaf table, "layers": [leaf table per layer]}."""
+    return arch.load(config).shapes(config)
 
 
 def _leaf(key, shape, std, kind):
@@ -48,15 +32,17 @@ def _leaf(key, shape, std, kind):
 
 @functools.lru_cache(maxsize=None)
 def _maker(frozen: tuple):
-    """One jitted maker per set of shapes (``frozen`` is hashable)."""
-    n_layers, layer, top = frozen
+    """One jitted maker per set of shapes (``frozen`` is hashable).  Leaf
+    i of the top level takes ``fold_in(key, i)``; leaf j of layer li takes
+    ``fold_in(fold_in(key, 1000 + li), j)``, leaves in name order."""
+    layers, top = frozen
 
     def make(key):
         out = {}
         for i, (name, s) in enumerate(top):
             out[name] = _leaf(jax.random.fold_in(key, i), *s)
         out["layers"] = []
-        for li in range(n_layers):
+        for li, layer in enumerate(layers):
             lk = jax.random.fold_in(key, 1000 + li)
             out["layers"].append({
                 name: _leaf(jax.random.fold_in(lk, j), *s)
@@ -77,6 +63,6 @@ def seed_key(seed: int):
 def make_params(config: dict, seed: int):
     """The whole parameter tree on the default device, from ``seed``."""
     spec = shapes(config)
-    frozen = (spec["layers"], tuple(sorted(spec["layer"].items())),
+    frozen = (tuple(tuple(sorted(layer.items())) for layer in spec["layers"]),
               tuple(sorted(spec["top"].items())))
     return _maker(frozen)(seed_key(seed))

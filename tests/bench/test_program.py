@@ -50,7 +50,7 @@ def _synthetic():
     ev = trace.Events(ops={DEV: [("%fusion.1", 100, 100),
                                  ("%fusion.2", 600, 100)]},
                       spans=[("bench.window", 0, 1000)])
-    return program.ProgramReadings(
+    return Readings(
         config={}, device_kind="TPU v5 lite", events=ev, rec=Record(),
         reqs=[SimpleNamespace(due=0.0)] * 3,
         submitted=[(0, 10.0), (1, 10.0), (2, 10.0)], t_open=10.0,
@@ -112,7 +112,8 @@ def test_capture_keeps_the_thread_of_each_program_span(tmp_path):
     with t.span("submit.lock_wait"):
         pass
     jax.profiler.stop_trace()
-    spans = program.read_spans(str(sorted(tmp_path.rglob("*.xplane.pb"))[-1]))
+    spans = trace.read_xplane(
+        str(sorted(tmp_path.rglob("*.xplane.pb"))[-1])).program_spans
     assert sorted(n for n, *_ in spans) == [
         "hydra.loop.idle", "hydra.step", "hydra.submit.lock_wait"]
     assert len({s[3] for s in spans}) == 2
@@ -147,11 +148,12 @@ def _recorded_readings(cls, ev, **extra):
 
 def test_harness_readers_and_breakdown_read_the_recorded_trace_as_before(
         recorded):
-    names = [m["name"] for m in load_cell(CELL).per_layer]
+    names = [m["name"] for m in load_cell(CELL).per_layer
+             if m["name"] not in program.READERS]
     assert len(names) == 10
     plain = _recorded_readings(Readings, recorded)
     with_program = _recorded_readings(
-        program.ProgramReadings, recorded,
+        Readings, recorded,
         program=_synthetic().program, program_spans=_synthetic().program_spans)
     values = {n: load_reader(n)(plain) for n in names}
     assert values == {n: load_reader(n)(with_program) for n in names}
